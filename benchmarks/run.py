@@ -34,7 +34,9 @@ def _modules():
 
 
 def main() -> None:
+    from repro import compile_cache
     only = sys.argv[1] if len(sys.argv) > 1 else None
+    compile_cache.enable()
     mods = _modules()
     if only == "--list":
         for tag, mod in mods:

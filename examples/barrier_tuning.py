@@ -23,6 +23,7 @@ Two layers of the tuner API:
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import placement, sweep, tuning
 
 KEY = jax.random.PRNGKey(0)
@@ -107,4 +108,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

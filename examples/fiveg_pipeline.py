@@ -12,6 +12,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import fiveg
 from repro.kernels import ops, ref
 
@@ -28,7 +29,9 @@ def simulate():
               f"  sync={float(res['partial'].sync_fraction) * 100:.1f}%")
 
 
-def execute():
+def execute() -> dict:
+    """Run the kernels on an OFDM batch; returns their largest
+    absolute errors against numpy (asserting the tolerances)."""
     print("\n== TPU kernel pipeline (OFDM demod + beamforming) ==")
     rng = np.random.default_rng(0)
     n_rx, n_sc, n_beams = 8, 1024, 4
@@ -42,21 +45,27 @@ def execute():
     want = np.fft.fft(np.asarray(re) + 1j * np.asarray(im), axis=-1)
     np.testing.assert_allclose(np.asarray(fr)[:, idx], want.real,
                                rtol=1e-3, atol=2e-3)
-    print(f" FFT: {n_rx} x {n_sc}-pt radix-4 OK (max err "
-          f"{np.max(np.abs(np.asarray(fr)[:, idx] - want.real)):.2e})")
+    np.testing.assert_allclose(np.asarray(fi)[:, idx], want.imag,
+                               rtol=1e-3, atol=2e-3)
+    fft_err = float(np.max(np.abs(np.asarray(fr)[:, idx] - want.real)))
+    print(f" FFT: {n_rx} x {n_sc}-pt radix-4 OK (max err {fft_err:.2e})")
 
     # beamforming: (n_beams x n_rx) @ (n_rx x n_sc), pallas matmul
     coef = jnp.asarray(rng.standard_normal((n_beams, n_rx)), jnp.float32)
     beams_r = ops.matmul(coef, fr)
     beams_i = ops.matmul(coef, fi)
-    np.testing.assert_allclose(beams_r, np.asarray(coef) @ np.asarray(fr),
-                               rtol=1e-4, atol=1e-3)
-    print(f" beamforming: {n_beams} beams x {n_sc} subcarriers OK")
+    want_r = np.asarray(coef) @ np.asarray(fr)
+    np.testing.assert_allclose(beams_r, want_r, rtol=1e-4, atol=1e-3)
+    beam_err = float(np.max(np.abs(np.asarray(beams_r) - want_r)))
+    print(f" beamforming: {n_beams} beams x {n_sc} subcarriers OK "
+          f"(max err {beam_err:.2e})")
     print(" output power per beam:",
           np.round(np.mean(np.asarray(beams_r) ** 2
                            + np.asarray(beams_i) ** 2, axis=1), 1))
+    return {"fft_max_err": fft_err, "matmul_max_err": beam_err}
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     simulate()
     execute()

@@ -5,7 +5,7 @@
 import jax
 import jax.numpy as jnp
 
-from repro import configs, optim
+from repro import compile_cache, configs, optim
 from repro.core import barrier, barrier_sim, fiveg
 from repro.data import DataConfig, batch_for_model
 from repro.models import init_params, loss_fn
@@ -55,5 +55,6 @@ def train_demo(steps: int = 20):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     barrier_demo()
     train_demo()

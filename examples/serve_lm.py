@@ -9,7 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.launch import mesh as mesh_mod, steps
 from repro.models import init_params
 
@@ -67,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

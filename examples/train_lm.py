@@ -16,7 +16,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import optim
+from repro import compile_cache, optim
 from repro.core import collectives
 from repro.data import DataConfig, batch_for_model
 from repro.launch import mesh as mesh_mod, steps
@@ -92,4 +92,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

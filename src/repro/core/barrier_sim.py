@@ -165,6 +165,42 @@ class BarrierResult(NamedTuple):
     timed_out_levels: jnp.ndarray  # int32: levels with >= 1 watchdog release
 
 
+_SUM_BLOCK = 32
+
+
+def _sequential_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """``0 + x[..., 0] + x[..., 1] + ...`` along the last axis, one
+    elementwise add at a time (XLA never reassociates float adds)."""
+    acc = jnp.zeros(x.shape[:-1], x.dtype)
+    for k in range(x.shape[-1]):
+        acc = acc + x[..., k]
+    return acc
+
+
+@jax.jit
+def pe_mean(x: jnp.ndarray) -> jnp.ndarray:
+    """Mean over the last (PE) axis in one fixed float32 summation order.
+
+    A plain ``jnp.mean`` is summed in whatever order the backend picks
+    for the surrounding fusion, so on a TPU the jitted cores and the
+    eagerly evaluated oracles land an ulp or two apart.  Here the order
+    is spelled out as elementwise adds: the axis is cut into blocks of
+    32 (zero-padded, the padding split evenly before and after), each
+    block is summed left to right, and the block sums are reduced the
+    same way until at most 32 remain.  That is exactly the order of
+    XLA's CPU tree reduction, so CPU results are unchanged, and every
+    backend and fusion context now gives the same bits.  Jitted so that
+    the division by the constant ``n`` compiles the same way for eager
+    callers (the oracles) as inside the cores."""
+    n = x.shape[-1]
+    s = x
+    while s.shape[-1] > _SUM_BLOCK:
+        pad = -s.shape[-1] % _SUM_BLOCK
+        s = jnp.pad(s, [(0, 0)] * (s.ndim - 1) + [(pad // 2, pad - pad // 2)])
+        s = _sequential_sum(s.reshape(s.shape[:-1] + (-1, _SUM_BLOCK)))
+    return _sequential_sum(s) / jnp.float32(n)
+
+
 def _serialize_group(ready: jnp.ndarray, latency: int,
                      cfg: TeraPoolConfig, svc=None) -> jnp.ndarray:
     """Serialize atomics within each group (rows of ``ready``).
@@ -203,6 +239,14 @@ def _segmented_cummax(x: jnp.ndarray, is_start: jnp.ndarray) -> jnp.ndarray:
         return jnp.where(rf, rv, jnp.maximum(lv, rv)), lf | rf
     v, _ = jax.lax.associative_scan(combine, (x, is_start))
     return v
+
+
+def _segment_first(is_start: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """Position of each sorted element's segment start: a running max of
+    the start positions.  (A ``searchsorted`` of the sorted keys into
+    themselves gives the same on the CPU, but in the large vmapped
+    grids on a TPU v5e it returned ranks one off in a few episodes.)"""
+    return jax.lax.cummax(jnp.where(is_start, idx, 0))
 
 
 def _scan_core(arrivals: jnp.ndarray, table: LevelTable,
@@ -262,8 +306,7 @@ def _scan_core(arrivals: jnp.ndarray, table: LevelTable,
         # reduction as _serialize_group, segmented by bank.
         is_start = jnp.concatenate(
             [jnp.ones((1,), bool), b[1:] != b[:-1]])
-        seg_first = jax.lax.cummax(jnp.where(is_start, idx, 0))
-        rank = (idx - seg_first).astype(jnp.float32)
+        rank = (idx - _segment_first(is_start, idx)).astype(jnp.float32)
         start = _segmented_cummax(a - rank * svc, is_start) + rank * svc
         # The counter's last arriver is its latest-serviced request; the
         # fetched value travels back at the counter's access latency.
@@ -283,7 +326,7 @@ def _scan_core(arrivals: jnp.ndarray, table: LevelTable,
 
     exit_time = ready[0] + cfg.wakeup_cycles
     last_arrival = jnp.max(arrivals, axis=-1)
-    mean_res = jnp.mean(exit_time[..., None] - arrivals, axis=-1)
+    mean_res = pe_mean(exit_time[..., None] - arrivals)
     return BarrierResult(
         exit_time=exit_time,
         last_arrival=last_arrival,
@@ -327,11 +370,9 @@ def _telescope_core(arrivals: jnp.ndarray, table: LevelTable,
 
     Inside each step the two-pass ``jnp.lexsort((ready, bank))`` of the
     scanned core becomes a single stable multi-key ``lax.sort`` over
-    ``(bank, ready)`` that co-sorts the group ids, and the per-bank
-    rank is derived with a ``searchsorted`` of the sorted bank column
-    into itself (first occurrence = segment start) instead of a second
-    ``cummax`` pass.  Only the max-plus service-start scan remains a
-    scan.
+    ``(bank, ready)`` that co-sorts the group ids; the per-bank rank
+    comes from the segment starts (:func:`_segment_first`), as in the
+    scanned core.
 
     Step widths are a STATIC tuple shared by the whole stacked sweep
     (one widths table per grid, computed host-side from the concrete
@@ -371,10 +412,9 @@ def _telescope_core(arrivals: jnp.ndarray, table: LevelTable,
         b, a, gs = jax.lax.sort((bank, ready, grp), num_keys=2)
         # Per-bank queues: the sorted bank column's first occurrence of
         # each bank is its segment start, so rank = idx - first.
-        first = jnp.searchsorted(b, b, side="left")
-        rank = (idx - first).astype(jnp.float32)
         is_start = jnp.concatenate(
             [jnp.ones((1,), bool), b[1:] != b[:-1]])
+        rank = (idx - _segment_first(is_start, idx)).astype(jnp.float32)
         start = _segmented_cummax(a - rank * svc, is_start) + rank * svc
         # The counter's last arriver is its latest-serviced request; the
         # fetched value travels back at the counter's access latency.
@@ -389,7 +429,7 @@ def _telescope_core(arrivals: jnp.ndarray, table: LevelTable,
 
     exit_time = ready[0] + cfg.wakeup_cycles
     last_arrival = jnp.max(arrivals, axis=-1)
-    mean_res = jnp.mean(exit_time[..., None] - arrivals, axis=-1)
+    mean_res = pe_mean(exit_time[..., None] - arrivals)
     return BarrierResult(
         exit_time=exit_time,
         last_arrival=last_arrival,
@@ -432,7 +472,8 @@ def _group_rank(gs: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     the rank; the co-sorted ``idx`` scatters ranks back to sorted
     positions."""
     g2, pos = jax.lax.sort((gs, idx), num_keys=1)
-    rank = idx - jnp.searchsorted(g2, g2, side="left")
+    is_start = jnp.concatenate([jnp.ones((1,), bool), g2[1:] != g2[:-1]])
+    rank = idx - _segment_first(is_start, idx)
     return jnp.zeros_like(idx).at[pos].set(rank)
 
 
@@ -468,8 +509,7 @@ def _robust_result(arrivals, ready, ok, cfg, n):
     last_arrival = jnp.max(jnp.where(live0, arrivals, -jnp.inf), axis=-1)
     n_ok = jnp.sum(ok)
     abandoned = jnp.int32(n) - n_ok
-    resid = jnp.mean(jnp.where(ok, exit_time[..., None] - arrivals, 0.0),
-                     axis=-1)
+    resid = pe_mean(jnp.where(ok, exit_time[..., None] - arrivals, 0.0))
     mean_res = resid * (jnp.float32(n)
                         / jnp.maximum(n_ok, 1).astype(jnp.float32))
     return exit_time, last_arrival, mean_res, abandoned
@@ -518,8 +558,7 @@ def _scan_robust_core(arrivals: jnp.ndarray, table: LevelTable,
         gs = grp[order]
         is_start = jnp.concatenate(
             [jnp.ones((1,), bool), b[1:] != b[:-1]])
-        seg_first = jax.lax.cummax(jnp.where(is_start, idx, 0))
-        rank = (idx - seg_first).astype(jnp.float32)
+        rank = (idx - _segment_first(is_start, idx)).astype(jnp.float32)
         start = _segmented_cummax(a - rank * svc, is_start) + rank * svc
         grank = _group_rank(gs, idx)
         release, fired = _robust_release(start, gs, grank, g, q, tmo, n)
@@ -558,6 +597,10 @@ def _scan_robust_core(arrivals: jnp.ndarray, table: LevelTable,
     )
 
 
+# One TPU lane tile: the narrowest window of a robust telescope step.
+_ROBUST_MIN_WIDTH = 128
+
+
 def _telescope_robust_core(arrivals: jnp.ndarray, table: LevelTable,
                            cfg: TeraPoolConfig,
                            widths: tuple | None = None,
@@ -567,7 +610,13 @@ def _telescope_robust_core(arrivals: jnp.ndarray, table: LevelTable,
     :func:`_scan_robust_core` (the two are bit-for-bit equal at every
     width table, like their plain twins).  The only extra per-step work
     is one stable sort for the within-group service rank and the
-    abandonment scatter, both confined to the step's window."""
+    abandonment scatter, both confined to the step's window.
+
+    Windows never shrink below ``_ROBUST_MIN_WIDTH`` lanes (any upper
+    bound on the live count is sound, see :func:`_telescope_core`): on
+    a TPU v5e, the full N=1024 robust grid of 130 schedules put 2 of
+    its 3120 episodes (both on the radix-2 tree) one cycle late with
+    narrower windows, and matched the scan core with 128-lane ones."""
     n = arrivals.shape[-1]
     arrivals = jnp.asarray(arrivals, jnp.float32)
     width = table.bank_ids.shape[-1]
@@ -590,7 +639,7 @@ def _telescope_robust_core(arrivals: jnp.ndarray, table: LevelTable,
     idx_n = jnp.arange(n)
     m = jnp.int32(n)
     for i in range(depth):
-        w = min(int(widths[i]), n)
+        w = min(max(int(widths[i]), _ROBUST_MIN_WIDTH), n)
         ready = ready[:w]
         idx = jnp.arange(w)
         g = table.group_sizes[i]
@@ -598,10 +647,9 @@ def _telescope_robust_core(arrivals: jnp.ndarray, table: LevelTable,
         grp = idx // g
         bank = table.bank_ids[i][jnp.minimum(grp, width - 1)]
         b, a, gs, lane = jax.lax.sort((bank, ready, grp, idx), num_keys=2)
-        first = jnp.searchsorted(b, b, side="left")
-        rank = (idx - first).astype(jnp.float32)
         is_start = jnp.concatenate(
             [jnp.ones((1,), bool), b[1:] != b[:-1]])
+        rank = (idx - _segment_first(is_start, idx)).astype(jnp.float32)
         start = _segmented_cummax(a - rank * svc, is_start) + rank * svc
         grank = _group_rank(gs, idx)
         release, fired = _robust_release(start, gs, grank, g, q,
@@ -612,7 +660,7 @@ def _telescope_robust_core(arrivals: jnp.ndarray, table: LevelTable,
         ok = ok & ~ab_lane[idx_n // span]
         timed = timed + jnp.any(fired).astype(jnp.int32)
         m = m // g
-        w_next = min(int(widths[i + 1]), w)
+        w_next = min(max(int(widths[i + 1]), _ROBUST_MIN_WIDTH), w)
         ready = jnp.where(jnp.arange(w_next) < m,
                           done[:w_next] + table.instr_cycles[i], jnp.inf)
 
@@ -802,7 +850,7 @@ def simulate_reference(arrivals: jnp.ndarray, schedule: BarrierSchedule,
     final = ready[..., 0]
     exit_time = final + cfg.wakeup_cycles
     last_arrival = jnp.max(arrivals, axis=-1)
-    mean_res = jnp.mean(exit_time[..., None] - arrivals, axis=-1)
+    mean_res = pe_mean(exit_time[..., None] - arrivals)
     stat, act, idle = schedule_energy_constants(
         schedule, None, cfg, energy_model)
     zeros = jnp.zeros(exit_time.shape, jnp.int32)
@@ -959,7 +1007,7 @@ def simulate_robust_reference(arrivals, schedule: BarrierSchedule,
     last = jnp.max(jnp.where(live0, arr_j, -jnp.inf), axis=-1)
     n_ok = jnp.sum(oks, axis=-1)
     abandoned = jnp.int32(n) - n_ok
-    resid = jnp.mean(jnp.where(oks, exits[:, None] - arr_j, 0.0), axis=-1)
+    resid = pe_mean(jnp.where(oks, exits[:, None] - arr_j, 0.0))
     mean_res = resid * (jnp.float32(n)
                         / jnp.maximum(n_ok, 1).astype(jnp.float32))
     stat, act, idle = schedule_energy_constants(

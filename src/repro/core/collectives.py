@@ -73,20 +73,11 @@ def data_axes(mesh: jax.sharding.Mesh | jax.sharding.AbstractMesh,
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, manual: Sequence[str]):
-    """``jax.shard_map`` with ``manual`` axes, on any supported jax.
-
-    Newer jax spells partial-manual as ``axis_names={...}, check_vma=``;
-    jax < 0.5 spells it ``auto=frozenset(other axes), check_rep=`` in
-    ``jax.experimental.shard_map``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=set(manual), check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - set(manual)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+    """``jax.shard_map`` with only the ``manual`` axes in manual mode
+    (the rest stay auto, e.g. the tensor-parallel ``model`` axis)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(manual),
+                         check_vma=False)
 
 
 def make_factored_mesh(radix, *, multi_pod: bool = False,
@@ -120,13 +111,8 @@ def make_factored_mesh(radix, *, multi_pod: bool = False,
     names = tuple(f"data{i}" for i in range(len(sub)))
     shape = ((2,) if multi_pod else ()) + sub + (model,)
     axes = (("pod",) if multi_pod else ()) + names + ("model",)
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        # jax < 0.5: no AxisType / axis_types kwarg; all axes are Auto.
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # ---------------------------------------------------------------------------

@@ -297,8 +297,9 @@ def energy_reference(arrivals, schedule, cfg: TeraPoolConfig = DEFAULT,
             np.float32) + np.float32(cfg.wakeup_cycles)
 
     # The residency mean mirrors the cores' reduction (same values in,
-    # same jnp.mean out) so the final f32 ops agree bit for bit.
-    resid = jnp.mean(jnp.asarray(exits[:, None] - flat), axis=-1)
+    # same fixed-order mean out) so the final f32 ops agree bit for bit.
+    from .barrier_sim import pe_mean
+    resid = pe_mean(jnp.asarray(exits[:, None] - flat))
     energy = episode_energy(jnp.float32(static), jnp.float32(active),
                             jnp.float32(idle), n, resid)
     return jnp.asarray(energy).reshape(batch)

@@ -266,7 +266,7 @@ def simulate_placed_reference(arrivals, schedule: BarrierSchedule,
     serviced.  Pure numpy, per-episode Python loops: use only in tests
     and spot checks.
     """
-    from .barrier_sim import BarrierResult
+    from .barrier_sim import BarrierResult, pe_mean
     from .energy import (DEFAULT_ENERGY, episode_energy,
                          schedule_energy_constants)
     arr = np.asarray(arrivals, np.float32)
@@ -282,11 +282,10 @@ def simulate_placed_reference(arrivals, schedule: BarrierSchedule,
         np.float32) + wake
     exit_time = exits.reshape(batch)
     last = np.max(flat, axis=-1).reshape(batch)
-    # Same values in, same jnp.mean reduction as the cores — so the
+    # Same values in, same fixed-order mean as the cores — so the
     # residency-derived energy column agrees to the same precision as
     # the exit times themselves.
-    resid = jnp.mean(jnp.asarray(exits[:, None] - flat),
-                     axis=-1).reshape(batch)
+    resid = pe_mean(jnp.asarray(exits[:, None] - flat)).reshape(batch)
     stat, act, idle = schedule_energy_constants(
         schedule, placement, cfg, DEFAULT_ENERGY)
     zeros = jnp.zeros(batch, jnp.int32)

@@ -52,6 +52,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
 
 from . import barrier, barrier_sim
 from .barrier import LevelTable
@@ -307,14 +308,12 @@ def _sharded_grid(devices: tuple, body: str, cfg: TeraPoolConfig,
     mesh, cached per (devices, body, cfg, core, widths) so repeated
     sweeps reuse one compiled program per shape (the one-compile
     property now holds per device topology x width table)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(devices), ("sched",))
     fn = {"sweep": _sweep_body, "arrival": _arrival_body}[body]
-    mapped = shard_map(partial(fn, cfg=cfg, core=core, widths=widths),
-                       mesh=mesh,
-                       in_specs=(P("sched"), P(), P()),
-                       out_specs=P("sched"))
+    mapped = jax.shard_map(partial(fn, cfg=cfg, core=core, widths=widths),
+                           mesh=mesh,
+                           in_specs=(P("sched"), P(), P()),
+                           out_specs=P("sched"))
     return jax.jit(mapped, donate_argnums=(2,))
 
 
@@ -326,11 +325,9 @@ def _sharded_grid_2d(devices: tuple, shape: tuple, cfg: TeraPoolConfig,
     tables, the kernel axis shards the arrival stacks, and each of the
     ``ds * dk`` devices simulates its (S/ds, K/dk) block of the grid.
     Outputs are (S, K, T) arrays sharded over both leading axes."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
     ds, dk = shape
     mesh = Mesh(np.asarray(devices).reshape(ds, dk), ("sched", "kern"))
-    mapped = shard_map(
+    mapped = jax.shard_map(
         partial(_arrival_body, cfg=cfg, core=core, widths=widths),
         mesh=mesh,
         in_specs=(P("sched"), P(), P("kern")),

@@ -7,6 +7,14 @@ TPU one *stage* is one pallas_call (grid = independent FFT rows — the
 dataflow rather than a barrier); ops.fft4 chains the log4(N) stages.
 Complex math is carried as separate re/im planes (TPU has no complex
 VREGs); twiddles are precomputed per stage by ops.py.
+
+The stage permutation (the paper's bank stores between stages) is a
+reshape/transpose around the kernel: each row is split into its four
+quarter planes ``a, b, c, d`` (every butterfly's four inputs sit at
+the same lane of the four planes), so the kernel body is purely
+elementwise on lane-dense ``(rows, n/4)`` tiles — the TPU compiler
+accepts no in-kernel reshape down to the short sub-transform lengths
+of the late stages.
 """
 from __future__ import annotations
 
@@ -18,14 +26,8 @@ ROW_TILE = 8
 
 
 def _stage_kernel(re_ref, im_ref, wr_ref, wi_ref, or_ref, oi_ref):
-    rows, n = re_ref.shape
-    q = wr_ref.shape[1]               # quarter length of sub-transform
-    re = re_ref[...].reshape(rows, -1, 4, q)
-    im = im_ref[...].reshape(rows, -1, 4, q)
-    ar, ai = re[:, :, 0], im[:, :, 0]
-    br, bi = re[:, :, 1], im[:, :, 1]
-    cr, ci = re[:, :, 2], im[:, :, 2]
-    dr, di = re[:, :, 3], im[:, :, 3]
+    ar, br, cr, dr = (re_ref[j] for j in range(4))
+    ai, bi, ci, di = (im_ref[j] for j in range(4))
     t0r, t0i = ar + cr, ai + ci
     t1r, t1i = ar - cr, ai - ci
     t2r, t2i = br + dr, bi + di
@@ -37,14 +39,29 @@ def _stage_kernel(re_ref, im_ref, wr_ref, wi_ref, or_ref, oi_ref):
     def cmul(xr, xi, yr, yi):
         return xr * yr - xi * yi, xr * yi + xi * yr
 
-    y0r, y0i = t0r + t2r, t0i + t2i
     y1r, y1i = cmul(t1r + t3r, t1i + t3i, w1r, w1i)
     y2r, y2i = cmul(t0r - t2r, t0i - t2i, w2r, w2i)
     y3r, y3i = cmul(t1r - t3r, t1i - t3i, w3r, w3i)
-    or_ref[...] = jnp.stack([y0r, y1r, y2r, y3r], axis=2
-                            ).reshape(rows, n)
-    oi_ref[...] = jnp.stack([y0i, y1i, y2i, y3i], axis=2
-                            ).reshape(rows, n)
+    for j, (yr, yi) in enumerate(((t0r + t2r, t0i + t2i), (y1r, y1i),
+                                  (y2r, y2i), (y3r, y3i))):
+        or_ref[j] = yr
+        oi_ref[j] = yi
+
+
+def _to_planes(x: jnp.ndarray, q: int) -> jnp.ndarray:
+    """(rows, n) -> (4, rows, n/4): plane j holds input j of every
+    butterfly, sub-transform blocks laid end to end."""
+    rows, n = x.shape
+    return (x.reshape(rows, n // (4 * q), 4, q).transpose(2, 0, 1, 3)
+            .reshape(4, rows, n // 4))
+
+
+def _from_planes(y: jnp.ndarray, q: int) -> jnp.ndarray:
+    """Inverse of :func:`_to_planes` (output j of a butterfly back to
+    quarter j of its sub-transform)."""
+    _, rows, quarter = y.shape
+    return (y.reshape(4, rows, quarter // q, q).transpose(1, 2, 0, 3)
+            .reshape(rows, 4 * quarter))
 
 
 def fft4_stage(re: jnp.ndarray, im: jnp.ndarray, wr: jnp.ndarray,
@@ -54,17 +71,19 @@ def fft4_stage(re: jnp.ndarray, im: jnp.ndarray, wr: jnp.ndarray,
     rows, n = re.shape
     bt = min(ROW_TILE, rows)
     q = wr.shape[1]
-    out_shape = [jax.ShapeDtypeStruct((rows, n), jnp.float32)] * 2
-    return pl.pallas_call(
+    quarter = n // 4
+    # Twiddles repeat per sub-transform block along the plane.
+    wr = jnp.tile(wr, (1, quarter // q)).reshape(3, 1, quarter)
+    wi = jnp.tile(wi, (1, quarter // q)).reshape(3, 1, quarter)
+    plane = pl.BlockSpec((4, bt, quarter), lambda i: (0, i, 0))
+    twiddle = pl.BlockSpec((3, 1, quarter), lambda i: (0, 0, 0))
+    out_shape = [jax.ShapeDtypeStruct((4, rows, quarter), jnp.float32)] * 2
+    yr, yi = pl.pallas_call(
         _stage_kernel,
         grid=(pl.cdiv(rows, bt),),
-        in_specs=[
-            pl.BlockSpec((bt, n), lambda i: (i, 0)),
-            pl.BlockSpec((bt, n), lambda i: (i, 0)),
-            pl.BlockSpec((3, q), lambda i: (0, 0)),
-            pl.BlockSpec((3, q), lambda i: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((bt, n), lambda i: (i, 0))] * 2,
+        in_specs=[plane, plane, twiddle, twiddle],
+        out_specs=[plane, plane],
         out_shape=out_shape,
         interpret=jax.default_backend() != "tpu",
-    )(re, im, wr, wi)
+    )(_to_planes(re, q), _to_planes(im, q), wr, wi)
+    return _from_planes(yr, q), _from_planes(yi, q)

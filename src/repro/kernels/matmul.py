@@ -23,7 +23,10 @@ def _mm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # HIGHEST: f32 operands take the full-precision MXU passes, not the
+    # TPU's default single bf16 pass.
     acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == n_k - 1)

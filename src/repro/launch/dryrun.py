@@ -24,7 +24,7 @@ from .. import configs   # noqa: E402
 from ..core import collectives  # noqa: E402
 from ..models.config import SHAPES_BY_NAME, applicable_shapes, skip_reason  # noqa: E402
 from . import hlo_analysis, roofline, steps  # noqa: E402
-from .mesh import make_production_mesh, mesh_context  # noqa: E402
+from .mesh import make_production_mesh  # noqa: E402
 
 HBM_PER_CHIP = 16 * 1024 ** 3   # v5e
 
@@ -62,7 +62,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                                         max_len=shape.seq_len)
         args = steps.input_specs(cfg, shape, mesh)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
     rec["lower_s"] = round(time.time() - t0, 1)
     t1 = time.time()
@@ -82,8 +82,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     rec["memory"]["fits_16gib"] = bool(resident <= HBM_PER_CHIP)
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax < 0.5: one dict per program
-        cost = cost[0] if cost else {}
     rec["xla_cost"] = {k: float(v) for k, v in cost.items()
                       if k in ("flops", "bytes accessed")}
 

@@ -13,13 +13,8 @@ import jax
 
 
 def _mk(shape, axes):
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        # jax < 0.5: no AxisType / axis_types kwarg; all axes are Auto.
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,13 +27,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_smoke_mesh():
     """1x1 mesh with the production axis names for CPU tests."""
     return _mk((1, 1), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available; on jax < 0.5 the Mesh
-    object itself is the (legacy) context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
 
 
 def manual_axes(mesh) -> Tuple[str, ...]:

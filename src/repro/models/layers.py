@@ -91,12 +91,7 @@ def constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
     """``with_sharding_constraint`` that silently drops axes that are not
     present (single-device smoke tests) or not Auto (manual shard_map
     axes), so model code is mesh-agnostic."""
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is None:
-        # jax < 0.5: no ambient abstract mesh to interrogate; leave
-        # placement to the compiler (same as the empty-mesh case below).
-        return x
-    mesh = get_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return x
     auto = {n for n, t in zip(mesh.axis_names, mesh.axis_types)

@@ -5,71 +5,9 @@ distribution tests (tests/test_distribution.py) need several devices;
 ``tests/test_system.py::test_distribution_suite_multidevice`` re-runs
 them in a subprocess with REPRO_MULTIDEV=1, which this conftest turns
 into an 8-device host platform BEFORE jax initializes.
-
-When ``hypothesis`` is unavailable (the container does not ship it and
-nothing may be pip-installed), a minimal deterministic stand-in is
-registered instead: ``@given`` runs each property test over a fixed
-pseudo-random sample of the strategies (seeded, so failures reproduce).
-Only the slice of the API the suite uses is provided — ``given``,
-``settings``, ``strategies.integers/floats/sampled_from``.
 """
 import os
 
 if os.environ.get("REPRO_MULTIDEV"):
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                                + os.environ.get("XLA_FLAGS", ""))
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import random
-    import sys
-    import types
-
-    class _Strategy:
-        def __init__(self, sample):
-            self.sample = sample
-
-    def _integers(lo, hi):
-        return _Strategy(lambda rng: rng.randint(lo, hi))
-
-    def _floats(lo, hi):
-        return _Strategy(lambda rng: rng.uniform(lo, hi))
-
-    def _sampled_from(seq):
-        items = list(seq)
-        return _Strategy(lambda rng: rng.choice(items))
-
-    def _settings(max_examples=10, deadline=None, **_kw):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-        return deco
-
-    def _given(*strategies):
-        # NB: the wrapper must expose a ZERO-arg signature (no
-        # functools.wraps / __wrapped__), else pytest reads the original
-        # parameters and demands fixtures for them.
-        def deco(fn):
-            def wrapper():
-                n = getattr(wrapper, "_stub_max_examples", 10)
-                rng = random.Random(0)
-                for _ in range(n):
-                    fn(*[s.sample(rng) for s in strategies])
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            wrapper.hypothesis_stub = True
-            return wrapper
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.floats = _floats
-    _st.sampled_from = _sampled_from
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

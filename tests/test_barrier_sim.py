@@ -40,6 +40,22 @@ def test_schedule_products_cover_all_pes(radix, n_pes):
     assert lats == sorted(lats)
 
 
+@pytest.mark.parametrize("n", [16, 33, 100, 1000, 1024, 2048])
+def test_pe_mean_fixed_order(n):
+    """pe_mean spells out the order of XLA's CPU mean (so CPU results
+    keep their bits) and gives the same bits eagerly and inside a
+    vmapped jit, where a plain jnp.mean may be reordered."""
+    rng = np.random.default_rng(n)
+    x = (rng.random((6, n)) * 3000 + rng.random((6, n)) * 1e-3
+         ).astype(np.float32)
+    want = np.asarray(jnp.mean(jnp.asarray(x), axis=-1))
+    eager = np.asarray(barrier_sim.pe_mean(jnp.asarray(x)))
+    fused = np.asarray(jax.jit(jax.vmap(
+        lambda r: barrier_sim.pe_mean(r * 1.0)))(x))
+    np.testing.assert_array_equal(eager, want)
+    np.testing.assert_array_equal(fused, want)
+
+
 def test_invalid_radix_rejected():
     with pytest.raises(ValueError):
         barrier.kary_tree(3)

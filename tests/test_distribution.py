@@ -40,7 +40,7 @@ def _place(tree, plan, mesh):
 
 
 def _train_once(cfg, sync, mesh, tokens):
-    with mesh_mod.mesh_context(mesh):
+    with jax.set_mesh(mesh):
         fn, art = steps.build_train_step(cfg, mesh, sync=sync)
         params = _place(init_params(cfg, KEY), art["plan"].full, mesh)
         opt_state = jax.jit(
@@ -90,7 +90,7 @@ def test_sharded_matches_single_device():
 def test_serve_prefill_decode_sharded():
     cfg = _cfg()
     mesh = _mesh()
-    with mesh_mod.mesh_context(mesh):
+    with jax.set_mesh(mesh):
         pre, art = steps.build_prefill_step(cfg, mesh, batch=8, seq_len=32)
         params = _place(init_params(cfg, KEY), art["plan"].full, mesh)
         tokens = jax.device_put(
@@ -109,10 +109,6 @@ def test_serve_prefill_decode_sharded():
         assert jnp.isfinite(np.asarray(lg, np.float32)).all()
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax < 0.5: experimental shard_map aborts XLA compiling the "
-           "psum_scatter chain on the CPU backend")
 def test_tree_psum_equals_flat_psum():
     """core.collectives.tree_psum == lax.psum under any radix split."""
     mesh = _mesh()

@@ -401,6 +401,7 @@ def test_sharded_2d_grid_multidevice():
     path bit for bit."""
     env = dict(os.environ)
     env["REPRO_MULTIDEV"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + os.environ.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = str(REPO / "src")

@@ -252,6 +252,7 @@ def test_elastic_reshard_multidevice(tmp_path):
     second kill-then-resume on the shrunken mesh stays exact too, for
     both sweep_schedules and sweep_arrivals grids."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + os.environ.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = str(REPO / "src")

@@ -66,6 +66,7 @@ def test_distribution_suite_multidevice():
     """Re-run the sharded-step tests on an 8-device host platform."""
     env = dict(os.environ)
     env["REPRO_MULTIDEV"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO / "src")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
@@ -79,6 +80,7 @@ def test_dryrun_cell_cli(tmp_path):
     """The dry-run CLI lowers+compiles one real cell on the production
     512-device multi-pod mesh (the MINIMUM multi-pod requirement)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO / "src")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
