@@ -32,6 +32,7 @@ from typing import List, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .energy import DEFAULT_ENERGY, EnergyModel, schedule_energy_constants
 from .topology import DEFAULT, TeraPoolConfig
@@ -660,12 +661,20 @@ def stack_tables(schedules: Sequence[BarrierSchedule],
             f"{len(schedules)} schedules but {len(placements)} placements")
     depth = max(max_depth(n),
                 max(s.n_levels for s in schedules))
-    tables = [level_table(s, depth, cfg, placement=p,
-                          energy_model=energy_model)
-              for s, p in zip(schedules, placements)]
-    # Each row was fully validated when level_table built it; the
-    # stacked check keeps only the cheap group-size suffix test (no
-    # host sync of the big stacked latency columns on the hot
-    # sweep-setup path).
-    return validate_tail_padding(
-        jax.tree.map(lambda *xs: jnp.stack(xs), *tables), full=False)
+    with TraceAnnotation("repro.stack_tables"):
+        with TraceAnnotation("repro.stack_tables.rows",
+                             rows=len(schedules)) as span:
+            misses = _level_table_cached.cache_info().misses
+            tables = [level_table(s, depth, cfg, placement=p,
+                                  energy_model=energy_model)
+                      for s, p in zip(schedules, placements)]
+            span.set_metadata(
+                misses=_level_table_cached.cache_info().misses - misses)
+        with TraceAnnotation("repro.stack_tables.stack"):
+            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *tables)
+        # Each row was fully validated when level_table built it; the
+        # stacked check keeps only the cheap group-size suffix test (no
+        # host sync of the big stacked latency columns on the hot
+        # sweep-setup path).
+        with TraceAnnotation("repro.stack_tables.validate"):
+            return validate_tail_padding(stacked, full=False)

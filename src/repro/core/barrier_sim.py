@@ -249,6 +249,14 @@ def _segment_first(is_start: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.cummax(jnp.where(is_start, idx, 0))
 
 
+def _phase(level: int, phase: str):
+    """Named scope ``telescope.l<level>.<phase>`` around one phase of a
+    telescope step (sort, rank, scan, segmax, compact).  It sets only
+    the HLO ``op_name`` metadata, so a device trace can sum the fused
+    operations per phase; the compiled program is unchanged."""
+    return jax.named_scope(f"telescope.l{level}.{phase}")
+
+
 def _scan_core(arrivals: jnp.ndarray, table: LevelTable,
                cfg: TeraPoolConfig, widths: tuple | None = None
                ) -> BarrierResult:
@@ -409,23 +417,31 @@ def _telescope_core(arrivals: jnp.ndarray, table: LevelTable,
         # their +inf ready times sort to the back of any bank queue
         # they land in, so they never perturb live requests.
         bank = table.bank_ids[i][jnp.minimum(grp, width - 1)]
-        b, a, gs = jax.lax.sort((bank, ready, grp), num_keys=2)
+        with _phase(i, "sort"):
+            b, a, gs = jax.lax.sort((bank, ready, grp), num_keys=2)
         # Per-bank queues: the sorted bank column's first occurrence of
         # each bank is its segment start, so rank = idx - first.
-        is_start = jnp.concatenate(
-            [jnp.ones((1,), bool), b[1:] != b[:-1]])
-        rank = (idx - _segment_first(is_start, idx)).astype(jnp.float32)
-        start = _segmented_cummax(a - rank * svc, is_start) + rank * svc
+        with _phase(i, "rank"):
+            is_start = jnp.concatenate(
+                [jnp.ones((1,), bool), b[1:] != b[:-1]])
+            rank = (idx - _segment_first(is_start, idx)).astype(
+                jnp.float32)
+        with _phase(i, "scan"):
+            start = (_segmented_cummax(a - rank * svc, is_start)
+                     + rank * svc)
         # The counter's last arriver is its latest-serviced request; the
         # fetched value travels back at the counter's access latency.
-        last = jax.ops.segment_max(start, gs, num_segments=w)
-        done = last + table.latencies[i][jnp.minimum(idx, width - 1)]
+        with _phase(i, "segmax"):
+            last = jax.ops.segment_max(start, gs, num_segments=w)
+            done = last + table.latencies[i][jnp.minimum(idx, width - 1)]
         # Survivors run the compare/branch + counter-reset + next-level
         # setup, then compact into the next (shrunken) window.
         m = m // g
         w_next = min(int(widths[i + 1]), w)
-        ready = jnp.where(jnp.arange(w_next) < m,
-                          done[:w_next] + table.instr_cycles[i], jnp.inf)
+        with _phase(i, "compact"):
+            ready = jnp.where(jnp.arange(w_next) < m,
+                              done[:w_next] + table.instr_cycles[i],
+                              jnp.inf)
 
     exit_time = ready[0] + cfg.wakeup_cycles
     last_arrival = jnp.max(arrivals, axis=-1)
@@ -646,23 +662,37 @@ def _telescope_robust_core(arrivals: jnp.ndarray, table: LevelTable,
         svc = table.service_cycles[i]
         grp = idx // g
         bank = table.bank_ids[i][jnp.minimum(grp, width - 1)]
-        b, a, gs, lane = jax.lax.sort((bank, ready, grp, idx), num_keys=2)
-        is_start = jnp.concatenate(
-            [jnp.ones((1,), bool), b[1:] != b[:-1]])
-        rank = (idx - _segment_first(is_start, idx)).astype(jnp.float32)
-        start = _segmented_cummax(a - rank * svc, is_start) + rank * svc
-        grank = _group_rank(gs, idx)
-        release, fired = _robust_release(start, gs, grank, g, q,
-                                         tmo_rows[i], w)
-        done = release + table.latencies[i][jnp.minimum(idx, width - 1)]
-        ab_lane = jnp.zeros((w,), bool).at[lane].set(start > release[gs])
-        span = jnp.int32(n) // m
-        ok = ok & ~ab_lane[idx_n // span]
-        timed = timed + jnp.any(fired).astype(jnp.int32)
-        m = m // g
-        w_next = min(max(int(widths[i + 1]), _ROBUST_MIN_WIDTH), w)
-        ready = jnp.where(jnp.arange(w_next) < m,
-                          done[:w_next] + table.instr_cycles[i], jnp.inf)
+        with _phase(i, "sort"):
+            b, a, gs, lane = jax.lax.sort((bank, ready, grp, idx),
+                                          num_keys=2)
+        with _phase(i, "rank"):
+            is_start = jnp.concatenate(
+                [jnp.ones((1,), bool), b[1:] != b[:-1]])
+            rank = (idx - _segment_first(is_start, idx)).astype(
+                jnp.float32)
+        with _phase(i, "scan"):
+            start = (_segmented_cummax(a - rank * svc, is_start)
+                     + rank * svc)
+        # The within-group rank's own stable sort counts as "rank".
+        with _phase(i, "rank"):
+            grank = _group_rank(gs, idx)
+        with _phase(i, "segmax"):
+            release, fired = _robust_release(start, gs, grank, g, q,
+                                             tmo_rows[i], w)
+            done = release + table.latencies[i][jnp.minimum(idx,
+                                                            width - 1)]
+        # Compaction here also scatters the abandoned lanes back to PEs.
+        with _phase(i, "compact"):
+            ab_lane = jnp.zeros((w,), bool).at[lane].set(
+                start > release[gs])
+            span = jnp.int32(n) // m
+            ok = ok & ~ab_lane[idx_n // span]
+            timed = timed + jnp.any(fired).astype(jnp.int32)
+            m = m // g
+            w_next = min(max(int(widths[i + 1]), _ROBUST_MIN_WIDTH), w)
+            ready = jnp.where(jnp.arange(w_next) < m,
+                              done[:w_next] + table.instr_cycles[i],
+                              jnp.inf)
 
     exit_time, last_arrival, mean_res, abandoned = _robust_result(
         arrivals, ready, ok, cfg, n)

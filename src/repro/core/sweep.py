@@ -52,6 +52,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import barrier, barrier_sim
@@ -349,11 +350,14 @@ def _dispatch_grid(body: str, tables: LevelTable, fixed: jnp.ndarray,
     is computed exactly once per chunk here and shared by all of them.
     """
     n_sched = tables.group_sizes.shape[0]
-    widths = barrier.telescope_widths(tables, block.shape[-1])
+    with TraceAnnotation("repro.sweep.widths"):
+        widths = barrier.telescope_widths(tables, block.shape[-1])
     if body.endswith("_robust"):
         shard = False    # robust grids run unsharded (traced FaultSpec
         #                  in the fixed slot; no shard_map spec for it)
-    with barrier_sim.quiet_donation():
+    # The span covers enqueueing the grid, not its device time.
+    with TraceAnnotation("repro.sweep.dispatch"), \
+            barrier_sim.quiet_donation():
         if body == "arrival" and shard:
             devs = (tuple(devices) if devices is not None
                     else tuple(jax.devices()))
@@ -381,6 +385,13 @@ def _trial_chunks(n_trials: int, trial_chunk: int | None):
         raise ValueError(f"trial_chunk must be >= 1, got {trial_chunk}")
     for lo in range(0, n_trials, trial_chunk):
         yield lo, min(lo + trial_chunk, n_trials)
+
+
+def _fresh(x: jnp.ndarray, index) -> jnp.ndarray:
+    """A fresh copy of one trial chunk of an input block, for the grid
+    to donate."""
+    with TraceAnnotation("repro.sweep.inputs"):
+        return jnp.copy(x[index])
 
 
 def _concat_results(parts: list) -> BarrierResult:
@@ -422,13 +433,15 @@ def sweep_schedules(key: jax.Array,
     schedules = tuple(schedules)
     tables = barrier.stack_tables(schedules, cfg, placements)
     n = schedules[0].n_pes
-    unit = jax.random.uniform(key, (n_trials, n), jnp.float32, 0.0, 1.0)
-    d = jnp.asarray(delays, jnp.float32)
+    with TraceAnnotation("repro.sweep.inputs"):
+        unit = jax.random.uniform(key, (n_trials, n), jnp.float32,
+                                  0.0, 1.0)
+        d = jnp.asarray(delays, jnp.float32)
     core = barrier_sim.resolve_core(core)
     body = "sweep" if faults is None else "sweep_robust"
     fixed = d if faults is None else (d, faults)
     res = _concat_results([
-        _dispatch_grid(body, tables, fixed, jnp.copy(unit[lo:hi]), cfg,
+        _dispatch_grid(body, tables, fixed, _fresh(unit, np.s_[lo:hi]), cfg,
                        core, shard, devices)
         for lo, hi in _trial_chunks(n_trials, trial_chunk)])
     # Placement-free sweeps keep the documented empty tuple (consumers
@@ -532,7 +545,8 @@ def sweep_arrivals(arrivals: jnp.ndarray,
     in the stacks themselves (see
     :func:`repro.core.workloads.apply_faults`).
     """
-    arrivals = jnp.asarray(arrivals, jnp.float32)
+    with TraceAnnotation("repro.sweep.inputs"):
+        arrivals = jnp.asarray(arrivals, jnp.float32)
     if arrivals.ndim == 2:
         arrivals = arrivals[None]
     if arrivals.ndim != 3:
@@ -557,8 +571,8 @@ def sweep_arrivals(arrivals: jnp.ndarray,
     fixed = jnp.zeros((0,), jnp.float32) if faults is None else faults
     res = _concat_results([
         _dispatch_grid(body, tables, fixed,
-                       jnp.copy(arrivals[:, lo:hi]), cfg, core, shard,
-                       devices)
+                       _fresh(arrivals, np.s_[:, lo:hi]), cfg, core,
+                       shard, devices)
         for lo, hi in _trial_chunks(n_trials, trial_chunk)])
     kernels = (tuple(kernels) if kernels is not None
                else tuple(f"workload{i}" for i in range(arrivals.shape[0])))

@@ -59,6 +59,7 @@ from typing import List, NamedTuple, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import barrier, placement as placement_mod, sweep
 from . import workloads as workloads_mod
@@ -238,9 +239,12 @@ def tune_barrier(key, n_pes: int | None = None,
     objectives: ``"p99_cycles"``, ``"worst_cycles"``,
     ``"completion"``).
     """
-    if schedules is None:
-        schedules = all_schedules(n_pes, cfg, prune=prune)
-    scheds, placs = _cross_placements(schedules, placements, cfg)
+    with TraceAnnotation("repro.tune.enumerate") as span:
+        if schedules is None:
+            schedules = all_schedules(n_pes, cfg, prune=prune)
+        scheds, placs = _cross_placements(schedules, placements, cfg)
+        span.set_metadata(n=scheds[0].n_pes if scheds else 0,
+                          rows=len(scheds))
     return sweep.sweep_schedules(key, scheds, delays, n_trials, cfg,
                                  placements=placs, core=core,
                                  trial_chunk=trial_chunk, shard=shard,

@@ -64,6 +64,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import barrier, sweep as sweep_mod, tuning, workloads
 from ..core import energy as energy_mod
@@ -197,11 +198,8 @@ class ServerStats:
     failed: int = 0
     backoff_seconds: float = 0.0
     faults: Dict[str, int] = dataclasses.field(default_factory=dict)
-
-    @property
-    def batch_efficiency(self) -> float:
-        """Mean requests per dispatch (1.0 = no batching win)."""
-        return self.batch_requests / self.batches if self.batches else 0.0
+    dequeued: int = 0             # requests the worker took off the queue
+    queue_wait_s: float = 0.0     # their summed submit -> dequeue wait
 
 
 class Ticket:
@@ -440,23 +438,25 @@ class TuningServer:
         """Admit one request; returns a :class:`Ticket` immediately.
         Raises :class:`ServerOverloaded` (with ``retry_after``) when the
         queue is full and :class:`ServerClosed` after shutdown began."""
-        pending = self._normalize(req)
-        with self._cond:
-            if self._closing:
-                raise ServerClosed("server is shutting down")
-            for other in self._queue:
-                if other.key == pending.key:
-                    ticket = Ticket()
-                    other.tickets.append(ticket)
-                    self.stats.deduped += 1
-                    return ticket
-            if len(self._queue) >= self.config.queue_depth:
-                self.stats.rejected += 1
-                raise ServerOverloaded(self._retry_after_locked())
-            self.stats.accepted += 1
-            self._queue.append(pending)
-            self._cond.notify_all()
-        return pending.tickets[0]
+        with TraceAnnotation("repro.serve.submit") as span:
+            pending = self._normalize(req)
+            span.set_metadata(seq=pending.seq)
+            with self._cond:
+                if self._closing:
+                    raise ServerClosed("server is shutting down")
+                for other in self._queue:
+                    if other.key == pending.key:
+                        ticket = Ticket()
+                        other.tickets.append(ticket)
+                        self.stats.deduped += 1
+                        return ticket
+                if len(self._queue) >= self.config.queue_depth:
+                    self.stats.rejected += 1
+                    raise ServerOverloaded(self._retry_after_locked())
+                self.stats.accepted += 1
+                self._queue.append(pending)
+                self._cond.notify_all()
+            return pending.tickets[0]
 
     def tune(self, req: TuneRequest,
              timeout: Optional[float] = None) -> TuneResponse:
@@ -530,18 +530,26 @@ class TuningServer:
     def _serve_loop(self) -> None:
         while True:
             with self._cond:
-                while not self._queue and not self._closing:
-                    self._cond.wait(0.1)
+                if not self._queue and not self._closing:
+                    with TraceAnnotation("repro.serve.idle"):
+                        while not self._queue and not self._closing:
+                            self._cond.wait(0.1)
                 if not self._queue:
                     return                   # closing and fully drained
                 if not self._closing and self.config.batch_window > 0:
-                    self._cond.wait(self.config.batch_window)
+                    with TraceAnnotation("repro.serve.batch_window",
+                                         queued=len(self._queue)):
+                        self._cond.wait(self.config.batch_window)
                 if not self._queue:
                     continue     # drained by a non-drain close mid-wait
                 batch = self._take_batch_locked()
                 self._processing = True
             try:
-                self._process(batch)
+                with TraceAnnotation("repro.serve.process",
+                                     batch=len(batch),
+                                     seq_first=batch[0].seq,
+                                     seq_last=batch[-1].seq):
+                    self._process(batch)
             except BaseException as e:       # never kill the worker
                 for p in batch:
                     if not p.done:
@@ -564,6 +572,9 @@ class TuningServer:
             else:
                 rest.append(p)
         self._queue = rest
+        now = self._clock()
+        self.stats.dequeued += len(batch)
+        self.stats.queue_wait_s += sum(now - p.submit_at for p in batch)
         return batch
 
     def _process(self, batch: List[_Pending]) -> None:
@@ -595,7 +606,8 @@ class TuningServer:
             return
         t0 = self._clock()
         try:
-            res, fault_counts = self._dispatch(ready)
+            with TraceAnnotation("repro.serve.dispatch"):
+                res, fault_counts = self._dispatch(ready)
         except Exception as e:
             self._note_batch_outcome(ok=False, fault_counts={})
             for p in ready:
@@ -607,26 +619,33 @@ class TuningServer:
         self._note_batch_outcome(ok=True, fault_counts=fault_counts)
         self.stats.batches += 1
         self.stats.batch_requests += len(ready)
-        winners = tuning.best_for_arrival_stack(
-            res, tuple(p.req.objective for p in ready))
-        slices = sweep_mod.split_kernels(res)
-        for p, win, piece in zip(ready, winners, slices):
-            payload = {
-                "pair": schedule_cache.encode_pair(
-                    win.schedule, win.placement, objective=p.req.objective),
-                "name": win.name,
-                "mean_span": win.mean_span,
-                "mean_energy": win.mean_energy,
-            }
-            self._memo[p.key] = payload
-            schedule_cache.store(p.key, payload)
-            self.stats.exact += 1
-            self._finish(p, TuneResponse(
-                schedule=win.schedule, placement=win.placement,
-                name=win.name, objective=p.req.objective,
-                provenance=BATCHED, tier=TIER_EXACT,
-                mean_span=win.mean_span, mean_energy=win.mean_energy,
-                batch_size=len(ready), result=piece))
+        # Selection pulls the grid's columns to the host first, so this
+        # span holds the worker's wait for the device to finish.
+        with TraceAnnotation("repro.serve.select"):
+            winners = tuning.best_for_arrival_stack(
+                res, tuple(p.req.objective for p in ready))
+        with TraceAnnotation("repro.serve.split"):
+            slices = sweep_mod.split_kernels(res)
+        with TraceAnnotation("repro.serve.respond"):
+            for p, win, piece in zip(ready, winners, slices):
+                payload = {
+                    "pair": schedule_cache.encode_pair(
+                        win.schedule, win.placement,
+                        objective=p.req.objective),
+                    "name": win.name,
+                    "mean_span": win.mean_span,
+                    "mean_energy": win.mean_energy,
+                }
+                self._memo[p.key] = payload
+                schedule_cache.store(p.key, payload)
+                self.stats.exact += 1
+                self._finish(p, TuneResponse(
+                    schedule=win.schedule, placement=win.placement,
+                    name=win.name, objective=p.req.objective,
+                    provenance=BATCHED, tier=TIER_EXACT,
+                    mean_span=win.mean_span,
+                    mean_energy=win.mean_energy,
+                    batch_size=len(ready), result=piece))
 
     # -- dispatch -----------------------------------------------------------
 

@@ -158,7 +158,7 @@ def test_batched_equals_unbatched_bit_for_bit():
         assert resp.schedule == want_sched and resp.placement == want_plc
         assert resp.mean_span == want_span
     assert srv.stats.batches == 1 and srv.stats.batch_requests == 3
-    assert srv.stats.batch_efficiency == 3.0
+    assert srv.stats.batch_requests / srv.stats.batches == 3.0
 
 
 def test_mixed_objectives_share_one_dispatch():
@@ -193,6 +193,34 @@ def test_dedup_is_idempotent():
     assert r1 is r2                       # one pending, one shared answer
     assert r1.provenance == BATCHED
     assert srv.stats.deduped == 1 and srv.stats.batches == 1
+
+
+def test_queue_wait_counted_on_the_server_clock(cache_env):
+    """``queue_wait_s`` sums each request's submit -> dequeue wait on the
+    server's clock; ``dequeued`` counts every request taken, a cache
+    hit as well as a swept one."""
+    now = [100.0]
+    srv = TuningServer(_cfg(), clock=lambda: now[0], start=False)
+    a = srv.submit(TuneRequest(arrivals=_trace(0)))
+    now[0] = 102.0
+    b = srv.submit(TuneRequest(arrivals=_trace(1)))
+    now[0] = 105.0
+    srv.start()
+    assert a.result(timeout=300).provenance == BATCHED
+    assert b.result(timeout=300).provenance == BATCHED
+    srv.close()
+    assert srv.stats.dequeued == 2
+    assert srv.stats.queue_wait_s == pytest.approx(5.0 + 3.0)
+
+    now[0] = 110.0
+    srv = TuningServer(_cfg(), clock=lambda: now[0], start=False)
+    c = srv.submit(TuneRequest(arrivals=_trace(0)))
+    now[0] = 111.5
+    srv.start()
+    assert c.result(timeout=300).provenance == CACHE_HIT
+    srv.close()
+    assert srv.stats.dequeued == 1 and srv.stats.batches == 0
+    assert srv.stats.queue_wait_s == pytest.approx(1.5)
 
 
 # ---------------------------------------------------------------------------
