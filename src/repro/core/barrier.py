@@ -549,9 +549,12 @@ def telescope_widths(table: LevelTable, n_pes: int) -> tuple | None:
 
 
 @functools.lru_cache(maxsize=None)
-def _level_table_cached(schedule: BarrierSchedule, max_levels: int,
-                        cfg: TeraPoolConfig, placement,
-                        energy_model: EnergyModel) -> LevelTable:
+def _host_level_table(schedule: BarrierSchedule, max_levels: int,
+                      cfg: TeraPoolConfig, placement,
+                      energy_model: EnergyModel) -> LevelTable:
+    """The validated table of one schedule as host (numpy) arrays:
+    :func:`stack_tables` stacks these rows on the host and sends each
+    stacked field to the device once."""
     n = schedule.n_pes
     width = counter_width(n)
     sizes = [lvl.group_size for lvl in schedule.levels]
@@ -609,16 +612,25 @@ def _level_table_cached(schedule: BarrierSchedule, max_levels: int,
     stat, act, idle = schedule_energy_constants(
         schedule, placement, cfg, energy_model)
     return validate_tail_padding(LevelTable(
-        group_sizes=jnp.asarray(sizes + [1] * pad, jnp.int32),
-        latencies=jnp.asarray(lat_rows, jnp.float32),
-        instr_cycles=jnp.asarray(instr + [0.0] * pad, jnp.float32),
-        bank_ids=jnp.asarray(bank_rows, jnp.int32),
-        service_cycles=jnp.asarray(svc + [0.0] * pad, jnp.float32),
-        entry_instr=jnp.float32(entry),
-        energy_static=jnp.asarray(stat, jnp.float32),
-        active_cycles=jnp.asarray(act, jnp.float32),
-        idle_power=jnp.asarray(idle, jnp.float32),
+        group_sizes=np.asarray(sizes + [1] * pad, np.int32),
+        latencies=np.asarray(lat_rows, np.float32),
+        instr_cycles=np.asarray(instr + [0.0] * pad, np.float32),
+        bank_ids=np.asarray(bank_rows, np.int32),
+        service_cycles=np.asarray(svc + [0.0] * pad, np.float32),
+        entry_instr=np.asarray(entry, np.float32),
+        energy_static=np.asarray(stat, np.float32),
+        active_cycles=np.asarray(act, np.float32),
+        idle_power=np.asarray(idle, np.float32),
     ))
+
+
+@functools.lru_cache(maxsize=None)
+def _level_table_cached(schedule: BarrierSchedule, max_levels: int,
+                        cfg: TeraPoolConfig, placement,
+                        energy_model: EnergyModel) -> LevelTable:
+    """The device copy of :func:`_host_level_table`, made once per row."""
+    return jax.device_put(_host_level_table(schedule, max_levels, cfg,
+                                            placement, energy_model))
 
 
 def level_table(schedule: BarrierSchedule, max_levels: int | None = None,
@@ -648,7 +660,10 @@ def stack_tables(schedules: Sequence[BarrierSchedule],
     """Stack the tables of same-``n_pes`` schedules along a new leading
     axis, ready to ``vmap`` one compiled simulate over the whole radix
     (or radix x placement) sweep.  ``placements`` aligns with
-    ``schedules``; ``None`` entries use the span-heuristic fallback."""
+    ``schedules``; ``None`` entries use the span-heuristic fallback.
+
+    The cached rows live on the host: each field is stacked there and
+    sent to the device in one transfer, not one dispatch per row."""
     if not schedules:
         raise ValueError("no schedules to stack")
     n = schedules[0].n_pes
@@ -664,17 +679,17 @@ def stack_tables(schedules: Sequence[BarrierSchedule],
     with TraceAnnotation("repro.stack_tables"):
         with TraceAnnotation("repro.stack_tables.rows",
                              rows=len(schedules)) as span:
-            misses = _level_table_cached.cache_info().misses
-            tables = [level_table(s, depth, cfg, placement=p,
-                                  energy_model=energy_model)
+            misses = _host_level_table.cache_info().misses
+            tables = [_host_level_table(s, depth, cfg, p, energy_model)
                       for s, p in zip(schedules, placements)]
             span.set_metadata(
-                misses=_level_table_cached.cache_info().misses - misses)
-        with TraceAnnotation("repro.stack_tables.stack"):
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *tables)
-        # Each row was fully validated when level_table built it; the
-        # stacked check keeps only the cheap group-size suffix test (no
-        # host sync of the big stacked latency columns on the hot
-        # sweep-setup path).
+                misses=_host_level_table.cache_info().misses - misses)
+        with TraceAnnotation("repro.stack_tables.stack") as span:
+            host = LevelTable(*(np.stack(f) for f in zip(*tables)))
+            stacked = jax.device_put(host)
+            span.set_metadata(bytes=sum(f.nbytes for f in host))
+        # Each row was fully validated when it was built; the stacked
+        # check keeps only the group-size suffix test, on the host copy.
         with TraceAnnotation("repro.stack_tables.validate"):
-            return validate_tail_padding(stacked, full=False)
+            validate_tail_padding(host, full=False)
+        return stacked

@@ -81,6 +81,11 @@ def test_tuner_spans_nest_as_documented(tmp_path):
     row_span, = _named(spans, "repro.stack_tables.rows")
     assert row_span["attrs"]["rows"] == rows
     assert row_span["attrs"]["misses"] == 0       # tables cached by run()
+    stack_span, = _named(spans, "repro.stack_tables.stack")
+    scheds, placs = tuning._cross_placements(
+        tuning.all_schedules(64, CFG), ("leaf_local", "central"), CFG)
+    table = barrier.stack_tables(scheds, CFG, placs)
+    assert stack_span["attrs"]["bytes"] == sum(f.nbytes for f in table)
     _stack_children_ok(spans)
     order = ["repro.tune.enumerate", "repro.stack_tables",
              "repro.sweep.inputs", "repro.sweep.widths",

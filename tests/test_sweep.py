@@ -7,8 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import barrier, barrier_sim, fiveg, sweep
-from repro.core.topology import DEFAULT
+from repro.core import (barrier, barrier_sim, fiveg, placement, sweep,
+                        tuning)
+from repro.core.topology import DEFAULT, TeraPoolConfig, multi_cluster
 
 KEY = jax.random.PRNGKey(0)
 
@@ -36,6 +37,55 @@ def test_stack_tables_shape_and_mismatch():
     with pytest.raises(ValueError):
         barrier.stack_tables([barrier.kary_tree(2, n_pes=64),
                               barrier.kary_tree(2, n_pes=128)])
+
+
+# A non-power-of-two cluster (8 x 12 x 8) and two of it side by side.
+C768 = TeraPoolConfig(n_pes=768, tiles_per_group=12, n_groups=8)
+C1536 = multi_cluster(C768, n_clusters=2)
+
+
+def _placed_stack(n_pes):
+    cfg = TeraPoolConfig(n_pes=n_pes)
+    scheds, placs = tuning._cross_placements(
+        tuning.all_schedules(n_pes, cfg), placement.STRATEGIES, cfg)
+    return scheds, cfg, placs
+
+
+def _hw_mixed_stack():
+    cfg = TeraPoolConfig(n_pes=64)
+    scheds = [barrier.kary_tree(2, n_pes=64, cfg=cfg),
+              barrier.kary_tree(8, n_pes=64, cfg=cfg),
+              barrier.hw_event_unit(64, cfg)]
+    placs = [None, placement.place_counters(scheds[1], "central", cfg),
+             None]
+    return scheds, cfg, placs
+
+
+@pytest.mark.parametrize("build", [
+    lambda: _placed_stack(64),
+    lambda: _placed_stack(256),
+    _hw_mixed_stack,
+    lambda: (tuning.all_schedules(768, C768, prune="hierarchy"), C768,
+             None),
+    lambda: (tuning.multicluster_schedules(C1536), C1536, None),
+], ids=["N64-placed", "N256-placed", "hw-mixed", "N768", "N1536-2cluster"])
+def test_stack_tables_equals_stacked_level_tables(build):
+    """The host-stacked table is the per-row device tables stacked:
+    same values, dtypes and shapes in every field."""
+    scheds, cfg, placs = build()
+    got = barrier.stack_tables(scheds, cfg, placs)
+    depth = max(barrier.max_depth(scheds[0].n_pes),
+                max(s.n_levels for s in scheds))
+    rows = [barrier.level_table(s, depth, cfg, placement=p)
+            for s, p in zip(scheds, placs or [None] * len(scheds))]
+    for row in rows:
+        assert all(isinstance(f, jax.Array) for f in row)
+    want = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
+    for name, g, w in zip(barrier.LevelTable._fields, got, want):
+        assert isinstance(g, jax.Array), name
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
 
 
 # ---------------------------------------------------------------------------
